@@ -271,6 +271,9 @@ def main() -> None:
     args = ap.parse_args()
     if args.full and args.fast:
         ap.error("--full and --fast are mutually exclusive")
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     fast = not args.full
     if args.json:  # fail on an unwritable path now, not after the run —
         # without creating an empty file a no-row run would leave behind

@@ -17,6 +17,7 @@ sys.path.insert(0, str(Path(__file__).parent.parent / "src"))
 import jax
 
 from repro.api import Session
+from repro.compile_cache import enable_compile_cache
 from repro.configs.resnet import RESNET18
 from repro.core import ClientInfo, NodeState, RoundConfig
 from repro.data import build_client_datasets, dirichlet_partition, synthetic_femnist
@@ -78,4 +79,5 @@ def main(rounds: int = 8):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main(rounds=4 if "--fast" in sys.argv[1:] else 8)

@@ -14,6 +14,7 @@ sys.path.insert(0, str(Path(__file__).parent.parent / "src"))
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.resnet import RESNET18
 from repro.core import (
     AggregatorPool,
@@ -82,4 +83,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

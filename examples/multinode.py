@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from repro.api import Session
+from repro.compile_cache import enable_compile_cache
 from repro.configs.resnet import RESNET18
 from repro.core import ClientInfo, RoundConfig
 from repro.data import build_client_datasets, dirichlet_partition, synthetic_femnist
@@ -130,4 +131,5 @@ def main(fast: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main(fast="--fast" in sys.argv)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api import Session
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS
 from repro.core import ClientInfo, NodeState, RoundConfig
 from repro.data import CohortTokenLoader, build_client_datasets, dirichlet_partition, synthetic_femnist
@@ -87,6 +88,7 @@ def part2_fused_round(rounds: int = 6):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     fast = "--fast" in sys.argv[1:]
     part1_paper_faithful(rounds=2 if fast else 4)
     part2_fused_round(rounds=2 if fast else 6)

@@ -24,6 +24,7 @@ sys.path.insert(0, str(Path(__file__).parent.parent / "src"))
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import ClientInfo, NodeState, RoundConfig
 from repro.obs import summary_line, to_prometheus
 from repro.runtime.netrt import RemoteRuntime, spawn_local_daemon
@@ -169,4 +170,5 @@ def main(fast: bool = False):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main(fast="--fast" in sys.argv)

@@ -16,6 +16,7 @@ import dataclasses
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS
 from repro.data import CohortTokenLoader
 from repro.fl.round import AggregationConfig
@@ -87,4 +88,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -33,7 +33,6 @@ hands out the single cached accumulator.
 """
 from __future__ import annotations
 
-import sys
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -359,23 +358,25 @@ class JaxEngine(AggregationEngine):
 
 
 def _auto_name() -> str:
-    """Pallas on TPU, blocked numpy on hosts — without importing jax."""
-    jx = sys.modules.get("jax")
-    if jx is not None:
-        try:
-            if jx.default_backend() == "tpu":
-                return "pallas"
-        except Exception:
-            pass
-    return "blocked"
+    """``auto`` by platform: the compiled Pallas fold on TPU, blocked
+    numpy on CPU hosts.  Any other platform raises rather than guess."""
+    import jax
+
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return "pallas"
+    if platform == "cpu":
+        return "blocked"
+    raise ValueError(f"agg_engine='auto' has no choice for platform "
+                     f"{platform!r}; pass one of {ENGINE_NAMES}")
 
 
 def make_engine(spec: Any = "auto", **kwargs) -> AggregationEngine:
     """Resolve an engine spec: an instance passes through (how the warm
     pool hands a resident engine to a fresh Aggregator), a name builds
     one, an :class:`EngineConfig` carries options (``block="auto"``
-    runs the one-shot tile autotune).  ``auto`` → pallas on TPU
-    backends, blocked numpy elsewhere."""
+    runs the one-shot tile autotune).  ``auto`` → pallas on TPU,
+    blocked numpy on CPU (:func:`_auto_name`)."""
     if isinstance(spec, AggregationEngine):
         return spec
     if isinstance(spec, EngineConfig):

@@ -15,7 +15,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size as compat_axis_size
 
 BLOCK = 256
 
@@ -62,8 +61,7 @@ def dequantize_tree(qs, meta, treedef, block: int = BLOCK):
 
 def _quantize_blocks_last_axis(x: jnp.ndarray, block: int):
     """Shape-preserving int8 block quantization along the last axis —
-    the wire format shared by the manual-pod ring exchange and the
-    0.4.x fallback's local roundtrip.  Returns (q int8, safe fp32
+    the wire format of the manual-pod ring exchange.  Returns (q int8, safe fp32
     scales, original last-axis length); dequantize with
     ``(q.astype(f32) * safe[..., None]).reshape(..)[..., :last]``."""
     xf = x.astype(jnp.float32)
@@ -102,7 +100,7 @@ def pod_mean_compressed(delta: Any, pod_axis: str, block: int = BLOCK) -> Any:
         # (all_gather's concatenated output loses the intra-pod sharding
         # under GSPMD and replicates — measured 334 s of DCN on kimi;
         # ppermute moves exactly shard_bytes × (P−1) per device)
-        n_pods = compat_axis_size(pod_axis)
+        n_pods = jax.lax.axis_size(pod_axis)
         perm = [(i, (i + 1) % n_pods) for i in range(n_pods)]
         acc = q.astype(jnp.float32) * safe[..., None]
         qc, sc = q, safe
@@ -113,22 +111,6 @@ def pod_mean_compressed(delta: Any, pod_axis: str, block: int = BLOCK) -> Any:
         deq = acc / n_pods
         out = deq.reshape(*padded_shape)[..., :last]
         return out.reshape(x.shape).astype(x.dtype)
-
-    return jax.tree.map(leaf, delta)
-
-
-def fake_quantize_tree(delta: Any, block: int = BLOCK) -> Any:
-    """Local int8 quantize→dequantize roundtrip per leaf — the wire
-    precision of :func:`pod_mean_compressed` without its collectives.
-    Used by the 0.4.x hierarchical fallback (no manual-`pod` region to
-    run the ring exchange in); blocks run along the last axis, matching
-    the on-the-wire layout."""
-
-    def leaf(x):
-        q, safe, last = _quantize_blocks_last_axis(x, block)
-        padded_shape = q.shape[:-2] + (q.shape[-2] * q.shape[-1],)
-        deq = (q.astype(jnp.float32) * safe[..., None]).reshape(*padded_shape)
-        return deq[..., :last].reshape(x.shape).astype(x.dtype)
 
     return jax.tree.map(leaf, delta)
 

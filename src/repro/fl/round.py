@@ -35,10 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import NESTED_SHARD_MAP_OK
-from repro.compat import shard_map as compat_shard_map
 from repro.configs.base import ArchConfig, ShapeConfig
-from repro.fl.compression import fake_quantize_tree, pod_mean, pod_mean_compressed
+from repro.fl.compression import pod_mean, pod_mean_compressed
 from repro.fl.server import apply_server_opt, init_server_state
 from repro.launch.mesh import dp_axes as mesh_dp_axes
 from repro.launch.mesh import pod_axis as mesh_pod_axis
@@ -147,7 +145,7 @@ def build_train_step(
     opts: Optional[ModelOptions] = None,
 ):
     """-> (train_step(params, server_state, batch) -> (params', state', metrics),
-           model).  Call under ``repro.compat.use_mesh(mesh)`` / lower with shardings
+           model).  Call under ``jax.set_mesh(mesh)`` / lower with shardings
            from :func:`train_shardings`."""
     dp = mesh_dp_axes(mesh)
     pod = mesh_pod_axis(mesh)
@@ -172,48 +170,6 @@ def build_train_step(
     if pod is None or agg.hierarchy == "flat":
         return flat_step, model
 
-    if not NESTED_SHARD_MAP_OK:
-        # 0.4.x fallback: the manual-`pod` wrapper would nest shard_maps
-        # (the model shard_maps internally) and SIGFPE the partitioner.
-        # Same math, unrolled: one contiguous batch slice per pod (the
-        # blocks P('pod') sharding would hand each pod), per-pod deltas
-        # compressed/averaged exactly like the manual top-aggregator hop.
-        def hier_step_legacy(params, server_state, batch):
-            n_pods = mesh.shape["pod"]
-
-            def pod_slice(x, i):
-                # same contract as P('pod') sharding on the manual path:
-                # the batch must split evenly across pods (the shard_map
-                # version errors on a ragged split; don't silently drop)
-                assert x.shape[0] % n_pods == 0, (
-                    f"global batch {x.shape[0]} not divisible by "
-                    f"{n_pods} pods")
-                b = x.shape[0] // n_pods
-                return x[i * b:(i + 1) * b]
-
-            deltas, wsums, losses = [], [], []
-            for i in range(n_pods):
-                b_i = jax.tree.map(lambda x: pod_slice(x, i), batch)
-                d, w, l = accumulate_updates(model, params, b_i, agg)
-                if agg.compress == "int8":
-                    d = fake_quantize_tree(d)  # wire precision, no comm
-                deltas.append(d)
-                wsums.append(w)
-                losses.append(l)
-            delta = jax.tree.map(
-                lambda *xs: sum(xs[1:], xs[0]) / n_pods, *deltas
-            )
-            wsum = sum(wsums[1:], wsums[0])
-            loss = sum(losses[1:], losses[0]) / n_pods
-            new_params, new_state = apply_server_opt(
-                agg.server_opt, params, server_state, delta, lr=agg.server_lr
-            )
-            return new_params, new_state, _metrics(
-                delta, wsum, loss, agg.num_microbatches * n_pods
-            )
-
-        return hier_step_legacy, model
-
     # hierarchical: manual over `pod`, GSPMD-auto inside the pod
     def hier_step(params, server_state, batch):
         def per_pod(p, b):
@@ -228,7 +184,7 @@ def build_train_step(
             return delta, wsum, loss
 
         n_axes = jax.tree.map(lambda _: P(), params)
-        delta, wsum, loss = compat_shard_map(
+        delta, wsum, loss = jax.shard_map(
             per_pod,
             mesh=mesh,
             in_specs=(n_axes, jax.tree.map(lambda x: P("pod"), batch)),

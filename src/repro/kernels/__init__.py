@@ -7,6 +7,8 @@ ops.py jit wrapper and a ref.py pure-jnp oracle:
   quantize/        per-block int8 quant/dequant (DCN update compression)
   flash_attention/ blockwise online-softmax attention forward
 
-All validated against their oracles with interpret=True shape/dtype
-sweeps in tests/test_kernels.py.
+``impl.py`` resolves every wrapper's ``impl=`` (``"auto"``: compiled
+Pallas on TPU, the jnp oracle on CPU).  All validated against their
+oracles with interpret=True shape/dtype sweeps in tests/test_kernels.py,
+and compiled for a described TPU v5e in tests/test_tpu_compile.py.
 """

@@ -1,8 +1,8 @@
 """Jitted public wrappers for the fedavg kernels.
 
-``impl='auto'`` picks Pallas on TPU backends, the jnp twin elsewhere
-(CPU dry-run / tests); ``impl='pallas_interpret'`` runs the kernel body
-in Python for correctness tests.  Pytree helpers flatten an update
+``impl='auto'`` picks the compiled Pallas kernel on TPU and the jnp
+twin on CPU (``kernels/impl.py``); ``impl='pallas_interpret'`` runs the
+kernel body in Python for correctness tests.  Pytree helpers flatten an update
 pytree into the (K, N) layout the kernel streams.
 """
 from __future__ import annotations
@@ -24,18 +24,7 @@ from repro.kernels.fedavg.ref import (
     fedavg_accumulate_k_ref,
     fedavg_reduce_ref,
 )
-
-
-def _use_pallas(impl: str) -> Tuple[bool, bool]:
-    if impl == "auto":
-        return (jax.default_backend() == "tpu"), False
-    if impl == "pallas":
-        return True, False
-    if impl == "pallas_interpret":
-        return True, True
-    if impl == "jnp":
-        return False, False
-    raise ValueError(f"unknown impl {impl!r}")
+from repro.kernels.impl import use_pallas
 
 
 @partial(jax.jit, static_argnames=("impl",))
@@ -44,7 +33,7 @@ def fedavg_reduce(updates: jnp.ndarray, weights: jnp.ndarray,
     """Weighted mean of K stacked flat updates: (K,N) × (K,) -> (N,)."""
     wn = weights.astype(jnp.float32)
     wn = wn / jnp.maximum(jnp.sum(wn), 1e-30)
-    pallas, interp = _use_pallas(impl)
+    pallas, interp = use_pallas(impl)
     if pallas:
         return fedavg_reduce_pallas(updates, wn, interpret=interp)
     return fedavg_reduce_ref(updates, wn)
@@ -54,7 +43,7 @@ def fedavg_reduce(updates: jnp.ndarray, weights: jnp.ndarray,
 def eager_accumulate(acc: jnp.ndarray, update: jnp.ndarray, weight,
                      *, impl: str = "auto") -> jnp.ndarray:
     """acc += w·u, donated/aliased accumulator (zero-copy fold)."""
-    pallas, interp = _use_pallas(impl)
+    pallas, interp = use_pallas(impl)
     if pallas:
         return eager_accumulate_pallas(acc, update, weight, interpret=interp)
     return eager_accumulate_ref(acc, update, weight)
@@ -69,7 +58,7 @@ def fedavg_accumulate_k(acc: jnp.ndarray, updates: jnp.ndarray, weights,
     *sum*; the caller divides by Σ w at the end (cumulative averaging,
     §2.1), so eager bursts and lazy batches stay numerically aligned.
     """
-    pallas, interp = _use_pallas(impl)
+    pallas, interp = use_pallas(impl)
     if pallas:
         return fedavg_accumulate_k_pallas(acc, updates, weights,
                                           interpret=interp)

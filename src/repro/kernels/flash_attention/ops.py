@@ -12,6 +12,7 @@ from repro.kernels.flash_attention.flash_attention import (
     flash_attention_fwd_pallas,
 )
 from repro.kernels.flash_attention.ref import attention_ref
+from repro.kernels.impl import use_pallas
 
 
 @partial(
@@ -39,14 +40,12 @@ def flash_attention(
     qh = q.reshape(B, S, K * G, D).transpose(0, 2, 1, 3)   # (B,H,S,D)
     kh = k.transpose(0, 2, 1, 3)                            # (B,K,S,D)
     vh = v.transpose(0, 2, 1, 3)
-    interp = impl == "pallas_interpret" or (
-        impl == "auto" and jax.default_backend() != "tpu"
-    )
-    if impl == "jnp":
-        out = attention_ref(qh, kh, vh, scale=scale, window=window, causal=causal)
-    else:
+    pallas, interp = use_pallas(impl)
+    if pallas:
         out = flash_attention_fwd_pallas(
             qh, kh, vh, scale=scale, window=window, causal=causal,
             bq=bq, bk=bk, interpret=interp,
         )
+    else:
+        out = attention_ref(qh, kh, vh, scale=scale, window=window, causal=causal)
     return out.transpose(0, 2, 1, 3).reshape(B, S, K, G, Dv)

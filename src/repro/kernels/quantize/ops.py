@@ -2,29 +2,17 @@
 from __future__ import annotations
 
 from functools import partial
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.impl import use_pallas
 from repro.kernels.quantize.quantize import (
     QBLOCK,
     dequantize_pallas,
     quantize_pallas,
 )
 from repro.kernels.quantize.ref import dequantize_ref, quantize_ref
-
-
-def _use_pallas(impl: str) -> Tuple[bool, bool]:
-    if impl == "auto":
-        return (jax.default_backend() == "tpu"), False
-    if impl == "pallas":
-        return True, False
-    if impl == "pallas_interpret":
-        return True, True
-    if impl == "jnp":
-        return False, False
-    raise ValueError(f"unknown impl {impl!r}")
 
 
 @partial(jax.jit, static_argnames=("impl",))
@@ -34,7 +22,7 @@ def quantize(x: jnp.ndarray, *, impl: str = "auto"):
     nb = -(-n // QBLOCK)
     xp = jnp.pad(x.astype(jnp.float32).reshape(-1), (0, nb * QBLOCK - n))
     blocks = xp.reshape(nb, QBLOCK)
-    pallas, interp = _use_pallas(impl)
+    pallas, interp = use_pallas(impl)
     if pallas:
         q, s = quantize_pallas(blocks, interpret=interp)
     else:
@@ -45,7 +33,7 @@ def quantize(x: jnp.ndarray, *, impl: str = "auto"):
 @partial(jax.jit, static_argnames=("n", "impl"))
 def dequantize(q: jnp.ndarray, scales: jnp.ndarray, n: int,
                *, impl: str = "auto") -> jnp.ndarray:
-    pallas, interp = _use_pallas(impl)
+    pallas, interp = use_pallas(impl)
     if pallas:
         out = dequantize_pallas(q, scales, interpret=interp)
     else:

@@ -4,7 +4,8 @@ The cross-pod hop moves the aggregated update over slow DCN links;
 quantizing to int8 with one fp32 scale per 256-lane block cuts wire
 bytes ~4× (fp32) / ~2× (bf16).  Layout: flat N padded to blocks of
 ``QBLOCK``; kernel tiles ``ROWS_PER_CALL`` blocks per grid step so each
-VMEM slab is (rows, 256) — lane-aligned for the VPU.
+VMEM slab is (rows, 256) — lane-aligned for the VPU — and each scales
+block is (rows,), aligned to XLA's 1-D tiling.
 """
 from __future__ import annotations
 
@@ -15,7 +16,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 QBLOCK = 256          # elements per scale
-ROWS_PER_CALL = 256   # quant blocks per grid step -> (256, 256) VMEM slab
+# quant blocks per grid step -> (1024, 256) VMEM slab.  The (rows,)
+# scales block must be a multiple of 1024: XLA tiles a 1-D f32 array
+# T(1024) in HBM, and Mosaic refuses a block whose tiling disagrees
+ROWS_PER_CALL = 1024
 
 
 def _quant_kernel(x_ref, q_ref, s_ref):

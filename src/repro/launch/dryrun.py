@@ -32,7 +32,6 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis.hlo import collective_stats
-from repro.compat import use_mesh
 from repro.analysis.hlo_cost import parse_hlo_cost
 from repro.analysis.roofline import from_compiled
 from repro.configs import get_arch, get_shape, shape_applicable
@@ -155,7 +154,7 @@ def run_cell(
         opts = ModelOptions(**base)
 
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             step, model = build_train_step(cfg, mesh, agg, opts=opts)
             aparams = abstract_params(model)

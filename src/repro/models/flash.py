@@ -21,8 +21,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size as compat_axis_size
-from repro.compat import shard_map as compat_shard_map
 
 from repro.configs.base import GLOBAL
 
@@ -177,7 +175,7 @@ def flash_self_attention_sp(
     B, S = q.shape[:2]
 
     def body(qc, kc, vc):
-        shards = compat_axis_size(model_axis)
+        shards = jax.lax.axis_size(model_axis)
         L = S // shards
         idx = jax.lax.axis_index(model_axis)
         sq0 = idx * L
@@ -217,7 +215,7 @@ def flash_self_attention_sp(
 
     spec_q = P(dp_axes, model_axis, None, None, None)
     spec_kv = P(dp_axes, model_axis, None, None)
-    return compat_shard_map(
+    return jax.shard_map(
         body,
         in_specs=(spec_q, spec_kv, spec_kv),
         out_specs=spec_q,

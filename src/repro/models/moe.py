@@ -23,7 +23,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map as compat_shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig, MoEConfig
@@ -134,7 +133,7 @@ def _moe_ep(
     body = partial(_ep_local, moe, model_axis=model_axis)
     tok_spec = P(dp_axes)  # (T, D): T sharded over data axes, D replicated
     w_spec = P(model_axis)  # (E, ...) sharded over model axis
-    return compat_shard_map(
+    return jax.shard_map(
         lambda g, u, d, x, gg, ii: body(g, u, d, x, gg, ii),
         in_specs=(w_spec, w_spec, w_spec, tok_spec, tok_spec, tok_spec),
         out_specs=tok_spec,

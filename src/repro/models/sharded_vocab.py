@@ -20,7 +20,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map as compat_shard_map
 from jax.sharding import PartitionSpec as P
 
 VOCAB_PAD_MULTIPLE = 256
@@ -53,7 +52,7 @@ def embed_lookup(table: jnp.ndarray, tokens: jnp.ndarray,
         # CHECK ("Invalid binary instruction opcode copy") in this path.
         return jax.lax.psum(rows.astype(jnp.float32), model_axis).astype(tbl.dtype)
 
-    return compat_shard_map(
+    return jax.shard_map(
         body,
         in_specs=(P(model_axis, None), P()),
         out_specs=P(),
@@ -125,7 +124,7 @@ def chunked_lm_loss_sharded(
         # f32 at the shard_map boundary: the transpose rule psums the
         # replicated-input cotangent over `model`, and bf16 collectives
         # hit an XLA:CPU float-normalization CHECK failure.
-        return compat_shard_map(
+        return jax.shard_map(
             lambda wc, hh, yy: _ce_chunk_local(
                 wc, hh, yy, vocab=vocab, tied=tied, model_axis=model_axis
             ),
@@ -170,7 +169,7 @@ def decode_logits(hidden: jnp.ndarray, w: jnp.ndarray, *, vocab: int,
         logits = hf @ (wf.T if tied else wf)  # (B, 1, v_loc)
         return jax.lax.all_gather(logits, model_axis, axis=2, tiled=True)
 
-    full = compat_shard_map(
+    full = jax.shard_map(
         body,
         in_specs=(w_spec, P()),
         out_specs=P(),

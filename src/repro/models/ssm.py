@@ -21,7 +21,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map as compat_shard_map
 
 from repro.configs.base import ArchConfig
 from repro.models.layers import dense_init
@@ -221,7 +220,7 @@ def ssm_scan_sharded(
 
     u_spec = P(dp_axes, None, model_axis)
     h_spec = P(dp_axes, model_axis, None)
-    y, h_final = compat_shard_map(
+    y, h_final = jax.shard_map(
         body,
         in_specs=(pspecs, u_spec, h_spec),
         out_specs=(u_spec, h_spec),

@@ -65,6 +65,7 @@ SPAN_KINDS: Tuple[str, ...] = (
     "dispatch",       # DISPATCH phase: the pump loop, contiguous
     "collect",        # COLLECT phase: waiting on outstanding subtrees
     "fold",           # FOLD phase: root-site fold orchestration
+    "release",        # close-out sweep of the round's store objects
     "client_train",   # Σ time pulling the updates generator (child of dispatch)
     "deliver",        # Σ time in runtime deliver/put_update (child of dispatch)
     "quiesce",        # runtime quiesce barrier (child of collect)
@@ -241,7 +242,8 @@ class RoundTrace:
                         exec: serialize, ring/socket, ship, waiting
           top_fold      measured root fold exec within the fold phase
           control       spawn + loop glue + fold orchestration overhead
-          unaccounted   wall − Σ(phases): inter-phase bookkeeping
+                        + the close-out release of store objects
+          unaccounted   wall − Σ(phases, release): inter-phase bookkeeping
 
         The six tiers sum to ``wall_s`` by construction; ``coverage``
         is the attributed fraction (acceptance: ≥ 0.95).
@@ -251,6 +253,7 @@ class RoundTrace:
         dispatch = self.sum_kind("dispatch")
         collect = self.sum_kind("collect")
         fold = self.sum_kind("fold")
+        release = self.sum_kind("release")
 
         train = min(self.sum_kind("client_train"), dispatch)
         deliver = min(self.sum_kind("deliver"), dispatch - train)
@@ -259,8 +262,9 @@ class RoundTrace:
         mid = min(self.sum_kind("fold.mid"), deliver + collect)
         wire = max(0.0, deliver + collect - mid)
         top = min(self.sum_kind("fold.top"), fold)
-        control = spawn + dispatch_other + max(0.0, fold - top)
-        unaccounted = max(0.0, wall - (spawn + dispatch + collect + fold))
+        control = spawn + dispatch_other + max(0.0, fold - top) + release
+        unaccounted = max(0.0, wall - (spawn + dispatch + collect + fold
+                                       + release))
         coverage = 1.0 - (unaccounted / wall) if wall > 0 else 0.0
         return {
             "wall_s": wall,

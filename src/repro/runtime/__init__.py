@@ -1,3 +1,5 @@
+"""Runtimes: the round driver, its in-process / shared-memory / multi-node
+backends, the typed event protocol, and (imported lazily) the trainers."""
 from repro.runtime.driver import (
     InProcRuntime,
     RoundDriver,
@@ -23,11 +25,20 @@ from repro.runtime.events import (
     from_wire,
     to_wire,
 )
-from repro.runtime.trainer import (
-    ClientRuntime,
-    FederatedTrainer,
-    FusedFLTrainer,
-)
+
+_TRAINER_NAMES = ("ClientRuntime", "FederatedTrainer", "FusedFLTrainer")
+
+
+def __getattr__(name):
+    # lazy: the trainers import jax, and host-side processes that only
+    # need the driver (netd, shmrt workers) must never load it — one
+    # process per chip owns the accelerator
+    if name in _TRAINER_NAMES:
+        from repro.runtime import trainer
+
+        return getattr(trainer, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
 
 __all__ = [
     "ArrivalTrace",

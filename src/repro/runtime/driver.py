@@ -1483,17 +1483,18 @@ class RoundHandle:
         # round, success or not — same sweep order as ever
         drv, st = self.driver, self.st
         rt = drv.runtime
-        for p in st.partials.values():
-            try:
-                rt.discard_partial(p.key)
-            except Exception:
-                pass
-        for keys in st.sent.values():
-            for key, _ in keys:
+        with drv.tracer.span("release", owner="driver", round_id=st.round_id):
+            for p in st.partials.values():
                 try:
-                    rt.discard_update(key)
+                    rt.discard_partial(p.key)
                 except Exception:
                     pass
+            for keys in st.sent.values():
+                for key, _ in keys:
+                    try:
+                        rt.discard_update(key)
+                    except Exception:
+                        pass
         if completed:
             drv.end_round(st.round_id)
         else:

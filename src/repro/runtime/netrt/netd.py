@@ -86,14 +86,22 @@ def _at_fork_close_daemon_sockets() -> None:
 
 os.register_at_fork(after_in_child=_at_fork_close_daemon_sockets)
 
+# netd folds on the host: a device engine would make every daemon claim
+# the accelerator that the controller process already holds (one
+# process per chip), so only the numpy engines are accepted here
+HOST_ENGINES = ("blocked", "naive")
+
 
 class NodeDaemon:
     """One node's frame-server front end over a local runtime."""
 
     def __init__(self, node: str, listen: str = "127.0.0.1:0", *,
-                 runtime: str = "inproc", agg_engine: str = "auto",
+                 runtime: str = "inproc", agg_engine: str = "blocked",
                  capacity: float = 20.0, poll_interval: float = 0.02,
                  compress: int = 0, fault_plan: Optional[FaultPlan] = None):
+        if agg_engine not in HOST_ENGINES:
+            raise ValueError(f"netd folds on the host: agg_engine must be "
+                             f"one of {HOST_ENGINES}, not {agg_engine!r}")
         self.node = node
         self.capacity = float(capacity)
         self.poll_interval = poll_interval
@@ -504,6 +512,9 @@ class NodeDaemon:
                 "published_keys": len(self._published),
                 "shm_bytes": _shm_bytes(
                     getattr(self.rt, "store_prefix", "")),
+                # host-side by construction: jax (and with it a device
+                # backend) must never load in a daemon
+                "jax_imported": "jax" in sys.modules,
             }
             if callable(rt_health):
                 health.update(rt_health())
@@ -570,7 +581,7 @@ def _shm_bytes(prefix: str) -> int:
 
 
 def spawn_local_daemon(node: str, *, runtime: str = "inproc",
-                       agg_engine: str = "auto", capacity: float = 20.0,
+                       agg_engine: str = "blocked", capacity: float = 20.0,
                        listen: str = "127.0.0.1:0", timeout: float = 30.0,
                        compress: int = 0, stdout=None,
                        fault_spec: Optional[FaultPlan] = None):
@@ -696,7 +707,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="host:port or unix:/path (port 0 = ephemeral)")
     ap.add_argument("--runtime", default="inproc",
                     choices=("inproc", "shmproc"))
-    ap.add_argument("--agg-engine", default="auto")
+    ap.add_argument("--agg-engine", default="blocked", choices=HOST_ENGINES)
     ap.add_argument("--capacity", type=float, default=20.0,
                     help="MC_i for the controller's placement model")
     ap.add_argument("--compress", type=int, default=0,

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import AsyncCheckpointer, latest_step, restore_checkpoint
-from repro.compat import use_mesh
 from repro.core import (
     ClientInfo,
     Coordinator,
@@ -598,7 +597,7 @@ class FusedFLTrainer:
 
     # ------------------------------------------------------------------
     def init(self, seed: int = 0) -> None:
-        with use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             self.params = self.model.init(jax.random.PRNGKey(seed))
             self.server_state = init_server_state(self.agg.server_opt, self.params)
 
@@ -618,7 +617,7 @@ class FusedFLTrainer:
     def train_round(self, batch: Dict[str, np.ndarray]) -> Dict[str, float]:
         assert self.params is not None, "call init() or maybe_restore() first"
         jb = {k: jnp.asarray(v) for k, v in batch.items()}
-        with use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             self.params, self.server_state, metrics = self.step_fn(
                 self.params, self.server_state, jb
             )

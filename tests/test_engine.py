@@ -241,6 +241,44 @@ def test_simulation_engine_speedup_strict():
         c.t_agg_for("warpdrive")
 
 
+# ---------------------------------------------------------------------------
+# "auto" resolves by platform and never falls back in silence
+# ---------------------------------------------------------------------------
+
+def test_auto_engine_is_blocked_numpy_on_cpu():
+    jax = pytest.importorskip("jax")
+    assert jax.default_backend() == "cpu"
+    assert isinstance(make_engine("auto"), BlockedNumpyEngine)
+
+
+@pytest.mark.parametrize("platform,expected", [("tpu", "pallas"),
+                                               ("cpu", "blocked")])
+def test_auto_engine_name_follows_platform(monkeypatch, platform, expected):
+    jax = pytest.importorskip("jax")
+    from repro.core.engine import _auto_name
+
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert _auto_name() == expected
+
+
+def test_auto_engine_unknown_platform_raises(monkeypatch):
+    jax = pytest.importorskip("jax")
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(ValueError, match="gpu"):
+        make_engine("auto")
+
+
+def test_auto_engine_backend_failure_propagates(monkeypatch):
+    jax = pytest.importorskip("jax")
+
+    def broken():
+        raise RuntimeError("backend init failed")
+
+    monkeypatch.setattr(jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="backend init failed"):
+        make_engine("auto")
+
+
 def test_object_store_meta():
     store = InProcObjectStore()
     x = RNG.normal(size=(17, 3)).astype(np.float32)

@@ -9,7 +9,6 @@ import numpy as np
 import pytest
 
 from conftest import tiny_opts
-from repro.compat import use_mesh
 from repro.configs import ARCHS
 from repro.fl.compression import dequantize_tree, quantize_tree
 from repro.fl.round import AggregationConfig, accumulate_updates, build_train_step
@@ -43,7 +42,7 @@ def test_eager_equals_lazy_aggregation():
     cfg, mesh, _, _, model = _setup()
     params = model.init(jax.random.PRNGKey(0))
     batch = _batch(cfg)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         de, we, _ = accumulate_updates(
             model, params, batch, AggregationConfig(timing="eager", num_microbatches=4)
         )
@@ -58,7 +57,7 @@ def test_eager_equals_lazy_aggregation():
 
 def test_train_step_decreases_loss():
     cfg, mesh, agg, step, model = _setup(micro=2)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         state = init_server_state("fedavg", params)
         jstep = jax.jit(step)
@@ -77,7 +76,7 @@ def test_server_optimizers_progress(opt):
     lr = {"fedavg": 1.0, "fedavgm": 0.7, "fedadam": 0.01}[opt]
     agg = dataclasses.replace(agg, server_lr=lr)
     step, model = build_train_step(cfg, mesh, agg, opts=tiny_opts(vocab_axis=None))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(0))
         state = init_server_state(opt, params)
         jstep = jax.jit(step)

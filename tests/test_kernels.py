@@ -138,6 +138,47 @@ def test_flash_pallas_vs_naive(B, S, K, G, D, window, dtype):
     )
 
 
+# ---------------------------------------------------------------------------
+# impl="auto": compiled Pallas on TPU, the jnp reference on CPU, never a
+# silent interpret-mode run
+# ---------------------------------------------------------------------------
+
+def test_flash_auto_on_cpu_runs_reference_not_interpreter(monkeypatch):
+    from repro.kernels.flash_attention import ops as fa_ops
+
+    def no_kernel(*args, **kwargs):
+        raise AssertionError("impl='auto' reached the Pallas kernel on CPU")
+
+    monkeypatch.setattr(fa_ops, "flash_attention_fwd_pallas", no_kernel)
+    B, S, K, G, D = 1, 96, 2, 1, 16     # a shape no other test traces
+    q = jnp.asarray(RNG.normal(size=(B, S, K, G, D)), jnp.float32)
+    k = jnp.asarray(RNG.normal(size=(B, S, K, D)), jnp.float32)
+    v = jnp.asarray(RNG.normal(size=(B, S, K, D)), jnp.float32)
+    out = flash_attention(q, k, v, scale=0.25, impl="auto")
+    ref = flash_attention(q, k, v, scale=0.25, impl="jnp")
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+@pytest.mark.parametrize("platform,expected", [
+    ("tpu", (True, False)), ("cpu", (False, False))])
+def test_auto_impl_follows_platform(monkeypatch, platform, expected):
+    from repro.kernels.impl import use_pallas
+
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert use_pallas("auto") == expected
+    assert use_pallas("pallas_interpret") == (True, True)
+
+
+def test_auto_impl_unknown_platform_raises(monkeypatch):
+    from repro.kernels.impl import use_pallas
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(ValueError, match="gpu"):
+        use_pallas("auto")
+    with pytest.raises(ValueError, match="unknown impl"):
+        use_pallas("warpdrive")
+
+
 def test_flash_vjp_matches_naive_grads():
     from repro.models.flash import flash_self_attention
     from repro.models.attention import _attend_naive

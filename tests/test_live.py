@@ -34,10 +34,7 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # vendored sampler shim — same API subset
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.api import Session  # noqa: E402
 from repro.core import ClientInfo, MetricsMap, NodeState, RoundConfig  # noqa: E402

@@ -28,7 +28,6 @@ def test_moe_ep_matches_dense_dispatch():
     high capacity factor)."""
     out = run_forced("""
         import jax, jax.numpy as jnp, numpy as np, dataclasses
-        from repro.compat import use_mesh
         from repro.launch.mesh import make_debug_mesh
         from repro.configs import ARCHS
         from repro.models import moe as moe_mod
@@ -40,7 +39,7 @@ def test_moe_ep_matches_dense_dispatch():
         key = jax.random.PRNGKey(0)
         params = moe_mod.init_moe(key, cfg, jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             yd, _ = moe_mod.moe_block(cfg, params, x, impl='dense')
             ye, _ = moe_mod.moe_block(cfg, params, x, impl='ep',
                                        dp_axes=('data',), model_axis='model')
@@ -57,7 +56,6 @@ def test_moe_ep_matches_dense_dispatch():
 def test_sharded_vocab_matches_dense():
     out = run_forced("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import use_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.launch.mesh import make_debug_mesh
         from repro.models.sharded_vocab import (
@@ -70,7 +68,7 @@ def test_sharded_vocab_matches_dense():
         toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, V - 7)
         hid = jax.random.normal(jax.random.PRNGKey(2), (B, S, D))
         labels = jax.random.randint(jax.random.PRNGKey(3), (B, S), 0, V - 7)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             # production paths are always jitted (eager shard_map with
             # partial-manual axes rejects unmentioned auto axes)
             e_sh = jax.jit(lambda t, k: embed_lookup(t, k, 'model'))(table, toks)
@@ -109,7 +107,6 @@ def test_hierarchical_equals_flat_aggregation_numerics():
     schedule changes, the math must not."""
     out = run_forced("""
         import jax, jax.numpy as jnp, numpy as np, dataclasses
-        from repro.compat import use_mesh
         from functools import partial
         from repro.configs import ARCHS, ShapeConfig
         from repro.fl.round import (AggregationConfig, build_train_step,
@@ -127,7 +124,7 @@ def test_hierarchical_equals_flat_aggregation_numerics():
         batch = {'tokens': jnp.asarray(toks, jnp.int32),
                  'labels': jnp.asarray(np.roll(toks, -1, 1), jnp.int32)}
         results = {}
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             for hier in ('flat', 'hierarchical'):
                 agg = AggregationConfig(hierarchy=hier, num_microbatches=2)
                 step, model = build_train_step(cfg, mesh, agg)
@@ -150,7 +147,6 @@ def test_hierarchical_equals_flat_aggregation_numerics():
 def test_int8_pod_compression_small_error():
     out = run_forced("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import use_mesh
         from repro.configs import ARCHS
         from repro.fl.round import AggregationConfig, build_train_step
         from repro.fl.server import init_server_state
@@ -163,7 +159,7 @@ def test_int8_pod_compression_small_error():
         batch = {'tokens': jnp.asarray(toks, jnp.int32),
                  'labels': jnp.asarray(np.roll(toks, -1, 1), jnp.int32)}
         outs = {}
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             for comp in ('none', 'int8'):
                 agg = AggregationConfig(hierarchy='hierarchical',
                                          compress=comp, num_microbatches=2)
@@ -190,7 +186,6 @@ def test_mini_dryrun_cell():
     out = run_forced("""
         import jax
         from functools import partial
-        from repro.compat import use_mesh
         from repro.analysis.hlo_cost import parse_hlo_cost
         from repro.configs import ARCHS, ShapeConfig
         from repro.fl.round import (AggregationConfig, abstract_params,
@@ -204,7 +199,7 @@ def test_mini_dryrun_cell():
         shape = ShapeConfig('t', 64, 8, 'train')
         agg = AggregationConfig(num_microbatches=2)
         dp = mdp(mesh)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             step, model = build_train_step(cfg, mesh, agg)
             ap = abstract_params(model)
             ps, ss = train_shardings(model, mesh, agg)

@@ -313,6 +313,52 @@ def test_daemon_survives_bad_frames(two_inproc_daemons):
 
 
 # ---------------------------------------------------------------------------
+# one process per chip: netd is host-side and never loads jax
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("engine", ["auto", "pallas", "jnp"])
+def test_netd_refuses_device_engines(engine):
+    from repro.runtime.netrt.netd import NodeDaemon
+
+    with pytest.raises(ValueError, match="host"):
+        NodeDaemon("x", agg_engine=engine)
+
+
+@pytest.mark.slow
+def test_netd_folds_a_round_without_importing_jax():
+    """A spawned shmproc daemon folds a full round (forked workers
+    included) and its live stats still say jax was never imported —
+    the controller is the only process that may claim the chip."""
+    if not os.path.isdir("/dev/shm"):
+        pytest.skip("POSIX shared memory required")
+    N = 2048
+    ups, ws = _mk_updates(6, N, seed=3)
+    procs, addrs = [], []
+    try:
+        for name in ("nodeA", "nodeB"):
+            p, a = _spawn_netd(name, "shmproc")
+            procs.append(p)
+            addrs.append(a)
+        rt = RemoteRuntime(addrs, agg_engine="blocked")
+        out = _drive(RoundDriver(rt), ["nodeA", "nodeB"], ups, ws, N, 0)
+        np.testing.assert_allclose(out.delta, fedavg_oracle(ups, ws),
+                                   rtol=1e-5, atol=1e-6)
+        stats = rt.poll_stats()
+        assert set(stats) == {"nodeA", "nodeB"}
+        for reply in stats.values():
+            assert reply["health"]["jax_imported"] is False
+            assert reply["workers"] >= 1
+        rt.shutdown_nodes()
+        rt.close()
+    finally:
+        for p in procs:
+            if p.poll() is None:
+                p.terminate()
+        for p in procs:
+            p.wait(timeout=10)
+
+
+# ---------------------------------------------------------------------------
 # Session-level multi-node + serve mode
 # ---------------------------------------------------------------------------
 

@@ -1,15 +1,8 @@
-"""Property-based tests (hypothesis) on system invariants.
-
-Runs under real hypothesis when installed; otherwise falls back to the
-vendored sampler shim (tests/_hypothesis_stub.py) so the invariants are
-exercised in every environment instead of skipping wholesale."""
+"""Property-based tests (hypothesis) on system invariants."""
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # no optional dep in the image: use the shim
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import repro.core as core
 from repro.core.aggregation import FedAvgState, fedavg_oracle
