@@ -29,6 +29,7 @@ from repro.core.engine import AggregationEngine, make_engine
 from repro.core.gateway import UpdateEnvelope
 from repro.core.objectstore import InProcObjectStore
 from repro.core.sidecar import EventSidecar
+from repro.obs.trace import NULL_TRACER, Tracer
 
 
 @dataclass
@@ -105,6 +106,8 @@ class Aggregator:
         on_complete: Optional[Callable[[np.ndarray, float], None]] = None,
         engine: Any = "auto",
         batch_k: int = 8,
+        tracer: Tracer = NULL_TRACER,
+        round_id: Optional[int] = None,
     ):
         self.agg_id = agg_id
         self.store = store
@@ -123,6 +126,9 @@ class Aggregator:
         # partials, not updates — state.count then carries the subtree
         # totals instead
         self.partials_absorbed = 0
+        # the engine's upload/read-back spans are this aggregator's
+        self.tracer = tracer
+        self._span_tags = {"owner": agg_id, "round_id": round_id}
 
     # ------------------------------------------------------------------
     # Recv step — called by the sockmap notify hook (event-driven)
@@ -130,10 +136,7 @@ class Aggregator:
     def recv(self, env: UpdateEnvelope) -> None:
         self.fifo.append(env)
         if self.sidecar:
-            self.sidecar.on_recv(
-                self.store.meta(env.object_key).nbytes,
-                time.perf_counter() - env.enqueue_ts,
-            )
+            self.sidecar.on_recv(self.store.meta(env.object_key).nbytes)
         if self.eager:
             # Recv ∥ Agg: fold immediately (App-G)
             self._drain()
@@ -148,6 +151,7 @@ class Aggregator:
         size 1; under lazy timing (or an arrival burst outpacing the
         fold) up to ``batch_k`` queued envelopes are folded in a single
         pass over the accumulator."""
+        self.engine.bind_trace(self.tracer, self._span_tags)
         while self.fifo and not self.done:
             k = min(len(self.fifo), self.batch_k, self.goal - self.state.count)
             if k <= 0:
@@ -178,10 +182,11 @@ class Aggregator:
         the store.  Folds immediately (the driver only routes partials
         here once every input is at hand, in plan order) and publishes
         when ``goal`` partials have been absorbed."""
+        self.engine.bind_trace(self.tracer, self._span_tags)
         t0 = time.perf_counter()
         view = np.asarray(self.store.get(key))
         if self.sidecar:
-            self.sidecar.on_recv(view.nbytes, 0.0)
+            self.sidecar.on_recv(view.nbytes)
         self.state.absorb(view, weight, count)
         self.store.release(key)
         self.engine.sync(self.state.acc)
@@ -198,6 +203,7 @@ class Aggregator:
     # ------------------------------------------------------------------
     def _send(self) -> None:
         self.done = True
+        self.engine.bind_trace(self.tracer, self._span_tags)
         self.result = self.state.result()
         if self.sidecar:
             self.sidecar.on_send(self.result[0].nbytes)

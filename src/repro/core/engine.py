@@ -39,6 +39,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs.trace import NULL_TRACER
+
 # Cache-sized tile: 64 Ki f32 = 256 KiB — acc block + update block +
 # scratch fit in L2 so the scratch round-trip never touches DRAM.
 BLOCK_ELEMS = 64 * 1024
@@ -124,6 +126,16 @@ class AggregationEngine:
         self.fold_calls = 0
         self.elements_folded = 0
         self.buffer_allocs = 0
+        self.tracer = NULL_TRACER
+        self.span_tags: Dict[str, Any] = {}
+
+    def bind_trace(self, tracer, span_tags: Dict[str, Any]) -> None:
+        """Attribute the engine's host↔device copy spans (``owner``,
+        ``round_id``) to whoever folds through it next.  A warm engine
+        serves the same tree position in every round, two rolling
+        rounds included, so each user binds before it folds."""
+        self.tracer = tracer
+        self.span_tags = span_tags
 
     # -- accumulator lifecycle -----------------------------------------
     def begin(self, n: int) -> Any:
@@ -322,8 +334,14 @@ class JaxEngine(AggregationEngine):
         self._count(1, update.size)
         # wire dtype rides to the device untouched; the kernel upcasts
         # to f32 in-register (accumulate-in-f32, any float wire dtype)
-        u = self._jnp.asarray(np.ascontiguousarray(update))
-        out = self._accumulate(acc, u, np.float32(w), impl=self.impl)
+        # the upload span lasts until the copy has landed; the kernel is
+        # queued behind the copy first, so waiting for it here costs the
+        # fold nothing
+        with self.tracer.span("fold.upload", n=float(update.nbytes),
+                              **self.span_tags):
+            u = self._jnp.asarray(np.ascontiguousarray(update))
+            out = self._accumulate(acc, u, np.float32(w), impl=self.impl)
+            u.block_until_ready()
         self._last = out
         return out
 
@@ -341,20 +359,33 @@ class JaxEngine(AggregationEngine):
         for i, u in enumerate(updates):          # row fill, no concat/stack
             np.copyto(slab[i], u, casting="unsafe")
         self._count(k, n)
-        out = self._accumulate_k(
-            acc,
-            self._jnp.asarray(slab[:k]),
-            self._jnp.asarray(np.asarray(weights, np.float32)),
-            impl=self.impl,
-        )
+        ws = np.asarray(weights, np.float32)
+        with self.tracer.span("fold.upload",
+                              n=float(slab[:k].nbytes + ws.nbytes),
+                              **self.span_tags):
+            rows = self._jnp.asarray(slab[:k])
+            dev_ws = self._jnp.asarray(ws)
+            out = self._accumulate_k(acc, rows, dev_ws, impl=self.impl)
+            self._jax.block_until_ready((rows, dev_ws))
         self._last = out
         return out
 
     def add_partial(self, acc, partial: np.ndarray):
-        return acc + self._jnp.asarray(np.asarray(partial, np.float32))
+        host = np.asarray(partial, np.float32)
+        with self.tracer.span("fold.upload", n=float(host.nbytes),
+                              **self.span_tags):
+            dev = self._jnp.asarray(host)
+            out = acc + dev
+            dev.block_until_ready()
+        return out
 
     def sync(self, acc) -> None:
         self._jax.block_until_ready(acc)
+
+    def to_numpy(self, acc) -> np.ndarray:
+        with self.tracer.span("readback", n=float(acc.nbytes),
+                              **self.span_tags):
+            return np.asarray(acc)
 
 
 def _auto_name() -> str:

@@ -37,7 +37,6 @@ class UpdateEnvelope:
     sender_id: str
     num_samples: float  # c_i^k — FedAvg weight
     model_version: int = 0
-    enqueue_ts: float = 0.0
 
 
 def serialize_update(update: np.ndarray, aux: Dict) -> bytes:
@@ -107,7 +106,7 @@ class Gateway:
         key = self.store.put(update)
         env = UpdateEnvelope(
             object_key=key, round_id=round_id, sender_id=sender_id,
-            num_samples=num_samples, enqueue_ts=time.perf_counter(),
+            num_samples=num_samples,
         )
         with self._lock:
             self.queue.append(env)

@@ -168,10 +168,9 @@ class EventSidecar:
         self.metrics.update(self.owner_id, "tx_bytes", float(nbytes))
         self.metrics.update(self.owner_id, "tx_msgs", 1.0)
 
-    def on_recv(self, nbytes: int, queue_delay_s: float) -> None:
+    def on_recv(self, nbytes: int) -> None:
         self.invocations += 1
         self.metrics.update(self.owner_id, "rx_bytes", float(nbytes))
-        self.metrics.update(self.owner_id, "queue_delay_s", queue_delay_s)
 
     def on_aggregate(self, n_updates: int, exec_time_s: float) -> None:
         """Execution time of the aggregation task — feeds E_{i,t} for the

@@ -12,6 +12,7 @@ auditable in one place.
 from repro.obs.export import summary_line, to_prometheus
 from repro.obs.live import FleetMonitor, Histogram, SLOTarget, SLOTracker
 from repro.obs.trace import (
+    MIRRORED_KINDS,
     NULL_TRACER,
     RoundTrace,
     SPAN_KINDS,
@@ -26,6 +27,7 @@ from repro.obs.trace import (
 __all__ = [
     "FleetMonitor",
     "Histogram",
+    "MIRRORED_KINDS",
     "NULL_TRACER",
     "RoundTrace",
     "SLOTarget",

@@ -71,12 +71,27 @@ SPAN_KINDS: Tuple[str, ...] = (
     "quiesce",        # runtime quiesce barrier (child of collect)
     "subtree",        # per-subtree first-dispatch → PartialReady latency
     "fold.mid",       # Σ measured mid-fold exec over absorbed partials
+    "fold.inner",     # Σ measured exec of a deep plan's inner fold stages
     "fold.top",       # measured root fold exec at the plan's root site
     "worker.task",    # shm worker: task pickup (ACK) → publish (PARTIAL)
     "worker.wait",    # shm worker: ring-pop wait inside the task (TELEM)
+    "store.put",      # one object-store put: an update or a published
+                      # partial (n = bytes; inside deliver's time)
+    "fold.upload",    # one host→device copy in JaxEngine (n = bytes)
+    "readback",       # one device→host read of an accumulator (n = bytes)
+    "server_step",    # the trainer's close-out: unflatten + leaf uploads,
+                      # server optimizer, finish_round, recycle (n = leaves)
+    "queue.wait",     # one update: queued by submit_update → pulled by
+                      # the serve feed (n = 1)
 )
 
 _SPAN_KIND_SET = frozenset(SPAN_KINDS)
+
+#: kinds whose begin and end run on one thread with no generator yield
+#: in between: only these are mirrored onto the profiler's clock (the
+#: round phases cross yields, where another round's code runs)
+MIRRORED_KINDS = frozenset({"store.put", "fold.upload", "readback",
+                            "server_step", "release", "quiesce"})
 
 
 def span_to_wire(span: Span) -> bytes:
@@ -105,9 +120,18 @@ class Tracer:
     """Edge-driven span recorder.  ``begin``/``end`` cost one clock read
     each; a disabled tracer costs one attribute load per hook and emits
     nothing, which is what ``bench_obs`` holds the enabled path against.
+
+    :meth:`mirror_to` puts the :data:`MIRRORED_KINDS` on a profiler's
+    clock too: while its session records, each such span is also an
+    annotation named ``lifl.<kind>``, opened just before the span's
+    first clock read and closed just after its last, on the thread that
+    ran it.  With no session recording the mirror costs one check per
+    span.  This module never imports the profiler: the caller, which
+    already has it, hands its annotation class in.
     """
 
-    __slots__ = ("enabled", "_clock", "_lock", "_spans", "_open", "_next")
+    __slots__ = ("enabled", "_clock", "_lock", "_spans", "_open", "_next",
+                 "_mirror")
 
     def __init__(self, enabled: bool = True, clock=time.perf_counter):
         self.enabled = enabled
@@ -116,6 +140,28 @@ class Tracer:
         self._spans: List[Span] = []
         self._open: Dict[int, tuple] = {}
         self._next = 0
+        self._mirror = None
+
+    def mirror_to(self, annotation) -> None:
+        """Mirror leaf spans onto a profiler: ``annotation(name)`` is a
+        context manager and ``annotation.is_enabled()`` says whether a
+        session records (``jax.profiler.TraceAnnotation`` is both).
+        ``None`` turns the mirror off."""
+        self._mirror = annotation
+
+    def annotate(self, kind: str):
+        """The entered profiler annotation that mirrors a span of
+        ``kind``, or None: no mirror, a kind that is not mirrored, or no
+        session recording.  :meth:`begin` uses it; so does a caller that
+        times an interval itself and files it with :meth:`point`, and
+        which exits the annotation where the interval ends."""
+        mirror = self._mirror
+        if (mirror is None or kind not in MIRRORED_KINDS
+                or not mirror.is_enabled()):
+            return None
+        note = mirror("lifl." + kind)
+        note.__enter__()
+        return note
 
     def begin(self, kind: str, owner: str = "", node: str = "",
               round_id: Optional[int] = None, parent: int = -1,
@@ -124,14 +170,20 @@ class Tracer:
         disabled — ``end(-1)`` is a no-op, so callers never branch)."""
         if not self.enabled:
             return -1
+        note = self.annotate(kind)
         t0 = self._clock()
         with self._lock:
             tok = self._next
             self._next += 1
-            self._open[tok] = (kind, owner, node, round_id, parent, worker, t0)
+            self._open[tok] = (kind, owner, node, round_id, parent, worker,
+                               t0, note)
         return tok
 
-    def end(self, token: int, n: float = 0.0) -> Optional[Span]:
+    def end(self, token: int, n: float = 0.0, *,
+            keep: bool = True) -> Optional[Span]:
+        """Close a span and return it.  ``keep=False`` leaves it out of
+        the buffer :meth:`drain` empties: for a span its caller files
+        itself, into a round's already sealed :class:`RoundTrace`."""
         if token < 0 or not self.enabled:
             return None
         t1 = self._clock()
@@ -139,11 +191,14 @@ class Tracer:
             opened = self._open.pop(token, None)
             if opened is None:
                 return None
-            kind, owner, node, round_id, parent, worker, t0 = opened
+            kind, owner, node, round_id, parent, worker, t0, note = opened
             span = Span(kind=kind, owner=owner, node=node,
                         round_id=round_id, t0=t0, dur_s=t1 - t0,
                         id=token, parent=parent, worker=worker, n=n)
-            self._spans.append(span)
+            if keep:
+                self._spans.append(span)
+        if note is not None:
+            note.__exit__(None, None, None)
         return span
 
     def point(self, kind: str, dur_s: float, owner: str = "",
@@ -164,12 +219,14 @@ class Tracer:
         return span
 
     @contextmanager
-    def span(self, kind: str, **kw) -> Iterator[int]:
+    def span(self, kind: str, n: float = 0.0, **kw) -> Iterator[int]:
+        """``begin``/``end`` around a block, closed (and its mirror with
+        it) however the block exits; ``n`` is the span's payload."""
         tok = self.begin(kind, **kw)
         try:
             yield tok
         finally:
-            self.end(tok)
+            self.end(tok, n=n)
 
     def add(self, span: Span) -> None:
         if not self.enabled:
@@ -185,9 +242,13 @@ class Tracer:
 
     def reset(self) -> None:
         """Drop any still-open spans — exception paths can abandon
-        begins; the driver resets between rounds so they can't pile up."""
+        begins; the driver resets between rounds so they can't pile up.
+        Their profiler annotations are closed here."""
         with self._lock:
-            self._open.clear()
+            opened, self._open = self._open, {}
+        for entry in opened.values():
+            if entry[-1] is not None:
+                entry[-1].__exit__(None, None, None)
 
 
 #: a process-wide disabled tracer, handed to components whose caller

@@ -64,7 +64,7 @@ from repro.core.placement import (
     split_agg_id,
 )
 from repro.core.sidecar import EventSidecar, MetricsMap
-from repro.obs.trace import RoundTrace, Tracer
+from repro.obs.trace import NULL_TRACER, RoundTrace, Tracer
 from repro.runtime.events import (
     GoalReached,
     PartialReady,
@@ -139,6 +139,23 @@ def _iter_feed(updates: Iterable) -> Callable[[], Any]:
     return feed
 
 
+def _timed_put(tracer: Tracer, put: Callable[[np.ndarray], str],
+               arr: np.ndarray) -> Tuple[str, float, float]:
+    """One object-store put, timed for a ``store.put`` span its caller
+    files with ``tracer.point``: returns the key, the start stamp and
+    the duration.  The put is a ``lifl.store.put`` profiler annotation
+    while a session records."""
+    note = tracer.annotate("store.put")
+    t0 = time.perf_counter()
+    try:
+        key = put(arr)
+    finally:
+        t1 = time.perf_counter()
+        if note is not None:
+            note.__exit__(None, None, None)
+    return key, t0, t1 - t0
+
+
 def _partial_node(rt, key: str) -> Optional[str]:
     """Which node a published partial physically lives on (None for
     single-node runtimes, where agg ids name logical nodes only)."""
@@ -186,6 +203,9 @@ class InProcRuntime(_WarmEngineMixin):
         self._events: Deque[RoundEvent] = deque()
         self.stats = {"cold_starts": 0, "warm_starts": 0, "crashes": 0}
         self._closed = False
+        # the driver's tracer once one drives this runtime: aggregators,
+        # their engines and the partial publish record spans into it
+        self.tracer: Tracer = NULL_TRACER
 
     # -- protocol -------------------------------------------------------
     def spawn_aggregator(self, agg_id: str, *, goal: int, n_elems: int,
@@ -201,6 +221,7 @@ class InProcRuntime(_WarmEngineMixin):
             sidecar=EventSidecar(agg_id, self.metrics),
             engine=self.engine_for(agg_id),
             on_complete=lambda *_args, a=agg_id: self._publish(a),
+            tracer=self.tracer, round_id=round_id,
         )
         self._open[agg_id] = (agg, round_id)
 
@@ -208,7 +229,12 @@ class InProcRuntime(_WarmEngineMixin):
         """Goal met: publish the raw partial sum Σ c·u into the store
         (one copy — the in-proc analogue of the shm seal+disown)."""
         agg, round_id = self._open.pop(agg_id)
-        key = self.store.put(np.asarray(agg.state.acc, dtype=np.float32))
+        tags = {"owner": agg_id, "round_id": round_id}
+        nbytes = 4.0 * agg.state.acc.size
+        with self.tracer.span("readback", n=nbytes, **tags):
+            acc = np.asarray(agg.state.acc, dtype=np.float32)
+        key, t0, put_s = _timed_put(self.tracer, self.store.put, acc)
+        self.tracer.point("store.put", put_s, n=nbytes, t0=t0, **tags)
         self._events.append(PartialReady(
             round_id=round_id, agg_id=agg_id, key=key,
             weight=agg.state.weight, count=agg.state.count,
@@ -217,8 +243,7 @@ class InProcRuntime(_WarmEngineMixin):
     def deliver(self, agg_id: str, key: str, weight: float,
                 round_id: int = 0) -> None:
         agg, _ = self._open[agg_id]
-        agg.recv(UpdateEnvelope(key, round_id, agg_id, weight,
-                                enqueue_ts=time.perf_counter()))
+        agg.recv(UpdateEnvelope(key, round_id, agg_id, weight))
 
     def deliver_partial(self, agg_id: str, key: str, weight: float,
                         count: int, round_id: int = 0, seq: int = 0) -> None:
@@ -558,13 +583,13 @@ class RoundDriver:
                  tracer: Optional[Tracer] = None,
                  trace_sink: Optional[Callable[[RoundTrace], None]] = None,
                  max_open_rounds: int = 1):
-        self.runtime = runtime
         self.metrics = metrics if metrics is not None else (
             runtime.metrics if runtime is not None else MetricsMap())
         # event-edge tracing (obs/): on by default — the enabled path is
         # FATAL-gated < 2% overhead (bench_obs); pass a disabled Tracer
         # to opt out entirely
-        self.tracer = tracer if tracer is not None else Tracer(enabled=True)
+        self._tracer = tracer if tracer is not None else Tracer(enabled=True)
+        self.runtime = runtime
         self.trace_sink = trace_sink
         self.last_trace: Optional[RoundTrace] = None
         # crash recovery gives up on a subtree after this many respawns
@@ -587,6 +612,33 @@ class RoundDriver:
             "events_dispatched": 0, "stale_dropped": 0,
             "deadline_ignored": 0, "crashes": 0, "redispatched": 0,
         }
+
+    # ------------------------------------------------------------------
+    # the one tracer reaches the runtime it drives
+    # ------------------------------------------------------------------
+    @property
+    def runtime(self) -> Optional[Any]:
+        return self._runtime
+
+    @runtime.setter
+    def runtime(self, rt: Optional[Any]) -> None:
+        self._runtime = rt
+        self._lend_tracer()
+
+    @property
+    def tracer(self) -> Tracer:
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer: Tracer) -> None:
+        self._tracer = tracer
+        self._lend_tracer()
+
+    def _lend_tracer(self) -> None:
+        # runtimes that record spans of their own (InProcRuntime) carry
+        # a ``tracer`` attribute; the others have nothing to lend it to
+        if hasattr(self._runtime, "tracer"):
+            self._runtime.tracer = self._tracer
 
     # ------------------------------------------------------------------
     # event bus
@@ -857,11 +909,14 @@ class RoundDriver:
         # --- DISPATCH: pump updates until the aggregation goal ---------
         # the pump is manually iterated so the two sub-costs the TTA
         # breakdown needs stay separable: pulling the feed IS the
-        # client's local training; put+deliver is the wire/store edge
+        # client's local training; put+deliver is the wire/store edge.
+        # Store puts are filed after the loop, as those two sums are:
+        # no tracer call runs between the multi-MB copies
         st.phase = "dispatch"
         tok = tr.begin("dispatch", owner="driver", round_id=round_id)
         train_s = deliver_s = 0.0
         pulls = delivers = 0
+        puts: List[Tuple[float, float, float]] = []   # (t0, dur, bytes)
         feed = updates if callable(updates) else _iter_feed(updates)
         while True:
             _t = time.perf_counter() if traced else 0.0
@@ -891,10 +946,10 @@ class RoundDriver:
                 # nothing planned / subtree given up / node full
                 out.skipped.append((node, client_id, flat, weight))
                 continue
-            _t = time.perf_counter() if traced else 0.0
-            key = rt.put_update(flat)
+            key, _t, put_s = _timed_put(tr, rt.put_update, flat)
             rt.deliver(agg_id, key, weight, round_id=round_id)
             if traced:
+                puts.append((_t, put_s, float(flat.nbytes)))
                 now = time.perf_counter()
                 deliver_s += now - _t
                 delivers += 1
@@ -915,6 +970,9 @@ class RoundDriver:
                      round_id=round_id, parent=tok, n=float(pulls))
             tr.point("deliver", deliver_s, owner="driver",
                      round_id=round_id, parent=tok, n=float(delivers))
+            for t0, put_s, nbytes in puts:
+                tr.point("store.put", put_s, owner="driver",
+                         round_id=round_id, parent=tok, n=nbytes, t0=t0)
         if accepted >= goal:
             self.dispatch(GoalReached(round_id=round_id, goal=goal,
                                       accepted=accepted))
@@ -994,6 +1052,8 @@ class RoundDriver:
         # produce the historical "top@node" byte for byte.
         top_id = join_agg_id("top", st.tag_job, st.tag_rid, top)
         engine = rt.engine_for(top_id)
+        engine.bind_trace(self.tracer,
+                          {"owner": top_id, "round_id": st.round_id})
         state = FedAvgState(engine=engine)
         state._ensure_acc(st.n_elems)
         sidecar = EventSidecar("top", self.metrics)
@@ -1258,6 +1318,8 @@ class RoundDriver:
             fold_dt = p.exec_s
         else:
             engine = rt.engine_for(root.agg_id)
+            engine.bind_trace(self.tracer, {"owner": root.agg_id,
+                                            "round_id": st.round_id})
             state = FedAvgState(engine=engine)
             state._ensure_acc(st.n_elems)
             sidecar = EventSidecar("top", self.metrics)

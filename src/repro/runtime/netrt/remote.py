@@ -725,7 +725,7 @@ class RemoteRuntime(_WarmEngineMixin):
         arr = np.frombuffer(
             frame.blob, dtype=resolve_dtype(frame.meta["dtype"]),
         ).reshape(frame.meta["shape"])
-        self._net_sidecar.on_recv(arr.nbytes, 0.0)
+        self._net_sidecar.on_recv(arr.nbytes)
         return arr
 
     def release_partial(self, key: str) -> None:

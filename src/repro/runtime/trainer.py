@@ -166,8 +166,10 @@ class FederatedTrainer:
         self.checkpoint_every = checkpoint_every
         self.log: List[Dict[str, float]] = []
         # externally submitted updates (Session.submit_update): each one
-        # takes a selected client's slot in the next round's cohort
-        self._external: Deque[Tuple[str, np.ndarray, float]] = deque()
+        # takes a selected client's slot in the next round's cohort.
+        # Items are (client_id, flat, weight, perf_counter when queued)
+        self._external: Deque[Tuple[str, np.ndarray, float, float]] = \
+            deque()
         # idempotent ingress: (client_id, submission_id) pairs already
         # accepted — a retried submission (lost ack, client backoff)
         # dedupes here instead of double-folding.  Bounded LRU so a
@@ -181,20 +183,28 @@ class FederatedTrainer:
             "stale_round": 0, "requeued": 0, "shed": 0}
         # externals popped by the current round's cohort generator —
         # the requeue pass matches them against RoundOutcome.skipped
-        self._popped_external: List[Tuple[str, np.ndarray, float]] = []
+        self._popped_external: List[
+            Tuple[str, np.ndarray, float, float]] = []
         # per-round traces (obs/): the driver's trace sink lands here;
         # bounded so a long job can't grow without limit.  trace_path
         # additionally appends each round as a JSONL record (flushed
-        # per line — post-mortems survive a mid-round kill).
+        # per line — post-mortems survive a mid-round kill).  A
+        # completed round's line waits for its server step, which runs
+        # after the driver sealed the trace: it is written when that
+        # step ends, so a kill inside the step loses that one line; a
+        # failed round has no server step and is written at once.
         self.trace_path = trace_path
         self.traces: "OrderedDict[int, RoundTrace]" = OrderedDict()
         self._traces_cap = 64
+        self._unwritten: Dict[int, RoundTrace] = {}
         self._runtime = None          # lazy: persists across rounds (warm)
         # an injected driver is shared infrastructure (serve mode): the
         # owner wires the coordinator's event handlers ONCE — wiring
         # them here per-trainer would double-count every EWMA sample
         self._driver: Optional[RoundDriver] = driver
         self._owns_driver = driver is None
+        if driver is not None:
+            _mirror_onto_profiler(driver)
         self._closed = False
 
     # ------------------------------------------------------------------
@@ -210,6 +220,7 @@ class FederatedTrainer:
                 raise RuntimeError("trainer is closed")
             self._driver = RoundDriver(metrics=self.metrics,
                                        trace_sink=self._sink_trace)
+            _mirror_onto_profiler(self._driver)
             # node churn reshapes the next plan, and every subtree's
             # PartialReady feeds its node's RC capacity model: the
             # coordinator is an ordinary event handler on the driver.
@@ -227,6 +238,17 @@ class FederatedTrainer:
         self.traces[trace.round_id] = trace
         while len(self.traces) > self._traces_cap:
             self.traces.popitem(last=False)
+        if trace.meta.get("completed"):
+            self._unwritten[trace.round_id] = trace   # finalize writes it
+            # bounded like ``traces``: rounds never finalized are written
+            # without their server step once they fall out
+            while len(self._unwritten) > self._traces_cap:
+                oldest = next(iter(self._unwritten))
+                self._write_trace(self._unwritten.pop(oldest))
+        else:
+            self._write_trace(trace)
+
+    def _write_trace(self, trace: RoundTrace) -> None:
         if self.trace_path:
             try:
                 write_trace(self.trace_path, trace)
@@ -287,7 +309,8 @@ class FederatedTrainer:
             self._seen_submissions[seen_key] = next_round
             while len(self._seen_submissions) > self._seen_submissions_cap:
                 self._seen_submissions.popitem(last=False)
-        self._external.append((client_id, flat, float(weight)))
+        self._external.append(
+            (client_id, flat, float(weight), time.perf_counter()))
         self.ingress["queued"] += 1
         return True
 
@@ -407,8 +430,9 @@ class FederatedTrainer:
 
         for cid, node in client_nodes.items():
             if self._external:
-                ext_cid, flat, weight = self._external.popleft()
-                self._popped_external.append((ext_cid, flat, weight))
+                item = self._external.popleft()
+                self._popped_external.append(item)
+                ext_cid, flat, weight, _queued = item
                 yield node, ext_cid, flat, weight
                 continue
             cr = self.clients[cid]
@@ -436,6 +460,10 @@ class FederatedTrainer:
         if self._closed:
             return
         self._closed = True
+        # completed rounds nobody finalized still get their line
+        for trace in self._unwritten.values():
+            self._write_trace(trace)
+        self._unwritten.clear()
         if self._runtime is not None:
             self._runtime.close()
             self._runtime = None
@@ -482,6 +510,45 @@ class _TrainerRound:
         if not self.handle.done:
             raise RuntimeError("round still in flight")
         tr, plan, outcome = self.trainer, self.plan, self.handle.outcome
+        rid = self.handle.round_id
+        tracer = tr.driver.tracer
+        tok = tracer.begin("server_step", owner=tr.job or "driver",
+                           round_id=rid)
+        try:
+            self._server_step()
+        finally:
+            # the driver sealed this round's trace before the server
+            # step ran: the span is filed there, not in the tracer's
+            # buffer, where the next round to close would claim it
+            span = tracer.end(tok, n=float(len(jax.tree.leaves(tr.params))),
+                              keep=False)
+            unwritten = tr._unwritten.pop(rid, None)
+            trace = unwritten if unwritten is not None else tr.traces.get(rid)
+            if span is not None and trace is not None:
+                trace.spans.append(span)
+            if unwritten is not None:
+                tr._write_trace(unwritten)
+
+        rec = {
+            "round": plan.round_id,
+            "updates": float(outcome.accepted),
+            "nodes_used": float(len(plan.placement.assignment)),
+            "inter_node": float(plan.inter_node_updates),
+            "cold_starts": float(outcome.cold_starts),
+            "reused": float(outcome.warm_starts),
+            "workers": float(outcome.workers),
+            "crashes": float(outcome.crashes),
+            "redispatched": float(outcome.redispatched),
+            "wall_s": time.perf_counter() - self.t0,
+        }
+        tr.log.append(rec)
+        self.record = rec
+        return rec
+
+    def _server_step(self) -> None:
+        """Requeue skipped externals, apply the aggregate to the params,
+        finish the round at the coordinator, recycle the engines."""
+        tr, plan, outcome = self.trainer, self.plan, self.handle.outcome
 
         # --- requeue skipped external submissions -----------------------
         # An external update the driver pulled but never dispatched
@@ -490,8 +557,7 @@ class _TrainerRound:
         # the next cohort instead.  Match by array identity — the same
         # object the generator yielded comes back in outcome.skipped.
         if outcome.skipped and tr._popped_external:
-            ext_ids = {id(flat): (cid, flat, w)
-                       for cid, flat, w in tr._popped_external}
+            ext_ids = {id(item[1]): item for item in tr._popped_external}
             requeued = [ext_ids[id(flat)]
                         for _node, _cid, flat, _w in outcome.skipped
                         if id(flat) in ext_ids]
@@ -522,21 +588,12 @@ class _TrainerRound:
         if not tr.driver._inflight:
             tr._runtime.recycle_engines()
 
-        rec = {
-            "round": plan.round_id,
-            "updates": float(outcome.accepted),
-            "nodes_used": float(len(plan.placement.assignment)),
-            "inter_node": float(plan.inter_node_updates),
-            "cold_starts": float(outcome.cold_starts),
-            "reused": float(outcome.warm_starts),
-            "workers": float(outcome.workers),
-            "crashes": float(outcome.crashes),
-            "redispatched": float(outcome.redispatched),
-            "wall_s": time.perf_counter() - self.t0,
-        }
-        tr.log.append(rec)
-        self.record = rec
-        return rec
+
+def _mirror_onto_profiler(driver: RoundDriver) -> None:
+    """The driver's leaf spans also become ``lifl.<kind>`` annotations
+    while a ``jax.profiler`` session records (see ``Tracer.mirror_to``)."""
+    if driver.tracer.enabled:
+        driver.tracer.mirror_to(jax.profiler.TraceAnnotation)
 
 
 def _flatten_tree(tree: Any) -> Tuple[np.ndarray, Any, list]:

@@ -166,12 +166,17 @@ class AggregationService:
             slots.extend([node] * len(plan.placement.assignment[node]))
         opened = time.perf_counter()
         state = {"last": opened, "n": 0}
+        tracer = self.driver.tracer
 
         def feed():
             now = time.perf_counter()
             if slots and tr._external:
-                cid, flat, w = tr._external.popleft()
-                tr._popped_external.append((cid, flat, w))
+                item = tr._external.popleft()
+                tr._popped_external.append(item)
+                cid, flat, w, queued = item
+                # queued by submit_update → pulled here, per update
+                tracer.point("queue.wait", now - queued, owner=cid,
+                             round_id=record["ticket"], t0=queued, n=1.0)
                 node = slots.popleft()
                 state["last"] = now
                 state["n"] += 1

@@ -32,7 +32,7 @@ def _updates(k=6, n=1000, dtype=np.float32):
 def _feed(agg, store, us, ws):
     for u, w in zip(us, ws):
         key = store.put(u)
-        agg.recv(UpdateEnvelope(key, 0, "c", w, enqueue_ts=0.0))
+        agg.recv(UpdateEnvelope(key, 0, "c", w))
 
 
 # ---------------------------------------------------------------------------

@@ -51,6 +51,24 @@ def test_every_span_kind_roundtrips_on_the_wire():
         assert span_from_wire(span_to_wire(s)) == s
         # str form decodes too (JSONL readers hand lines around as str)
         assert span_from_wire(span_to_wire(s).decode()) == s
+    # every kind a real round emits is registered: a deep (fanout-3)
+    # plan adds fold.inner, the in-process runtime the store, read-back
+    # and publish spans
+    from repro.core.placement import build_fold_plan
+
+    asg = {f"n{i:02d}": [2 * i, 2 * i + 1] for i in range(9)}
+    ups = [(f"n{i // 2:02d}", f"c{i}", np.full(8, float(i), np.float32), 1.0)
+           for i in range(18)]
+    rt = InProcRuntime()
+    drv = RoundDriver(rt)
+    drv.run_round(round_id=0, assignment=asg, updates=ups, goal=18,
+                  n_elems=8, fold_plan=build_fold_plan(
+                      asg, topology="controller", fanout=3))
+    rt.close()
+    emitted = drv.last_trace.spans
+    assert {"fold.inner", "store.put", "readback"} <= {s.kind for s in emitted}
+    for s in emitted:
+        assert span_from_wire(span_to_wire(s)) == s
 
 
 def test_span_wire_rejects_unknown_kinds():
