@@ -21,8 +21,11 @@ straight from /dev/shm (no stdlib resource tracker — see
 creator's lifetime and SIGKILLed workers are reclaimed by the
 dispatcher's name-prefix sweep.
 
-The single-process variant (``InProcObjectStore``) backs unit tests and
-the event-driven simulator without OS shared memory.
+The single-process variant (``InProcObjectStore``) backs the in-proc
+runtime, the event-driven simulator and unit tests without OS shared
+memory.  Both stores recycle: a deleted object's memory is parked on a
+size-keyed free list, under a guard that no reader still holds it, and
+the next put of that size writes into already-faulted pages.
 """
 from __future__ import annotations
 
@@ -31,6 +34,7 @@ import mmap
 import os
 import secrets
 import struct
+import sys
 import threading
 from dataclasses import dataclass, field
 from typing import Dict, Optional, Tuple
@@ -45,6 +49,17 @@ _HEADER_FMT = "<8s16sI4Q"
 _HEADER_BYTES = 64
 _MAGIC = b"LIFLOBJ1"
 _MAX_NDIM = 4
+
+
+def _sole_refs() -> int:
+    """What ``sys.getrefcount`` reads for an object that one local name
+    alone holds (the call's own reference differs across interpreters):
+    ``InProcObjectStore.delete``'s guard compares against it."""
+    obj = object()
+    return sys.getrefcount(obj)
+
+
+_SOLE_REFS = _sole_refs()
 
 
 def new_object_key() -> str:
@@ -556,26 +571,95 @@ class SharedMemoryObjectStore:
 
 
 class InProcObjectStore:
-    """Same interface, plain-dict backing (tests / simulator)."""
+    """Same interface, plain-dict backing: the store of ``InProcRuntime``
+    (the single-process runtime the chip path uses), the simulator
+    gateway and unit tests.
+
+    Each ``put`` copies the payload into an array the store owns and
+    marks it read-only (§4.1's immutable objects); ``get`` hands out
+    that array itself.  Recycling, as the shm store does with its
+    segments: ``delete`` parks the object's array on a free list keyed
+    by ``(nbytes, dtype)``, and a later ``put`` of that size copies
+    into it.  A long-lived runtime then writes each round's updates
+    into pages that are already faulted in (``np.copyto`` at memcpy
+    speed) instead of a fresh allocation whose first touch faults every
+    page, and its close-out frees nothing.  Keys are never reissued.
+
+    The guard, the in-proc analogue of the shm store's ``refcount ==
+    0``: an array is parked only when ``sys.getrefcount`` shows that
+    nothing outside the store holds it or a view of it (a ``get``
+    result, a memoryview, a CPU ``jnp.asarray`` that aliases it, a
+    transfer JAX has not let go of yet); otherwise it is dropped as
+    before and counted in ``stats["recycle_skipped"]``.  The bound:
+    parked bytes plus ``bytes_in_use`` never exceed ``peak_bytes``, the
+    highest ``bytes_in_use`` the store has reached, so it never holds
+    more host memory than its own peak would without recycling.
+    ``close()`` empties the free list.
+    """
 
     def __init__(self, node: str = "node0", capacity_bytes: int = 1 << 34):
         self.node = node
         self.capacity_bytes = capacity_bytes
         self._objs: Dict[str, np.ndarray] = {}
+        # (nbytes, dtype) -> parked arrays the store owns
+        self._free: Dict[Tuple[int, str], list] = {}
+        self._lock = threading.Lock()
         self.bytes_in_use = 0
-        self.stats = {"puts": 0, "gets": 0, "zero_copy_gets": 0, "evictions": 0}
+        self.parked_bytes = 0
+        self.peak_bytes = 0
+        self.stats = {"puts": 0, "gets": 0, "zero_copy_gets": 0,
+                      "evictions": 0, "recycled": 0, "recycle_skipped": 0}
 
     def put(self, array: np.ndarray, key: Optional[str] = None) -> str:
         key = key or new_object_key()
         arr = np.ascontiguousarray(array)
-        if self.bytes_in_use + arr.nbytes > self.capacity_bytes:
-            raise MemoryError(f"object store over capacity on {self.node}")
-        arr = arr.copy()
-        arr.flags.writeable = False  # immutable objects (paper §4.1)
-        self._objs[key] = arr
-        self.bytes_in_use += arr.nbytes
-        self.stats["puts"] += 1
+        with self._lock:
+            if self.bytes_in_use + arr.nbytes > self.capacity_bytes:
+                raise MemoryError(
+                    f"object store over capacity on {self.node}")
+            bucket = self._free.get((arr.nbytes, arr.dtype.str))
+            obj = bucket.pop() if bucket else None
+            # the bytes count as in use from here, so a concurrent put
+            # sees the copy this one is about to make
+            self.bytes_in_use += arr.nbytes
+            if obj is not None:
+                self.parked_bytes -= obj.nbytes
+            else:
+                self.peak_bytes = max(self.peak_bytes, self.bytes_in_use)
+                self._trim()
+        recycled = obj is not None
+        # the copy runs outside the lock: a parked array is the store's
+        # alone until it is filed under its new key
+        try:
+            if recycled:
+                obj.flags.writeable = True
+                obj.shape = arr.shape
+                np.copyto(obj, arr)
+            else:
+                obj = arr.copy()
+        except BaseException:
+            with self._lock:
+                self.bytes_in_use -= arr.nbytes
+            raise
+        obj.flags.writeable = False  # immutable objects (paper §4.1)
+        with self._lock:
+            self._objs[key] = obj
+            self.stats["puts"] += 1
+            self.stats["recycled"] += recycled
         return key
+
+    def _trim(self) -> None:
+        """Drop parked arrays until parked plus in-use bytes fit under
+        the peak again (a fresh put of another size can break the
+        bound; a delete or a recycled put cannot).  Caller holds the
+        lock."""
+        for size in list(self._free):
+            bucket = self._free[size]
+            while bucket and \
+                    self.parked_bytes + self.bytes_in_use > self.peak_bytes:
+                self.parked_bytes -= bucket.pop().nbytes
+            if not bucket:
+                del self._free[size]
 
     def get(self, key: str) -> np.ndarray:
         self.stats["gets"] += 1
@@ -586,10 +670,20 @@ class InProcObjectStore:
         pass
 
     def delete(self, key: str) -> None:
-        arr = self._objs.pop(key, None)
-        if arr is not None:
+        with self._lock:
+            arr = self._objs.pop(key, None)
+            if arr is None:
+                return
             self.bytes_in_use -= arr.nbytes
             self.stats["evictions"] += 1
+            # the guard: only the local name holds the array, so no
+            # reader can see its bytes rewritten by a later put
+            if sys.getrefcount(arr) <= _SOLE_REFS:
+                self._free.setdefault(
+                    (arr.nbytes, arr.dtype.str), []).append(arr)
+                self.parked_bytes += arr.nbytes
+            else:
+                self.stats["recycle_skipped"] += 1
 
     def contains(self, key: str) -> bool:
         return key in self._objs
@@ -602,5 +696,7 @@ class InProcObjectStore:
         )
 
     def close(self) -> None:
-        self._objs.clear()
-        self.bytes_in_use = 0
+        with self._lock:
+            self._objs.clear()
+            self._free.clear()
+            self.bytes_in_use = self.parked_bytes = self.peak_bytes = 0

@@ -156,6 +156,16 @@ def _timed_put(tracer: Tracer, put: Callable[[np.ndarray], str],
     return key, t0, t1 - t0
 
 
+def _store_counts(rt) -> Dict[str, int]:
+    """The runtime's in-proc store's put and recycled-put counters, for
+    a round's deltas; zeros where the runtime has no in-proc store."""
+    store = getattr(rt, "store", None)
+    if not isinstance(store, InProcObjectStore):
+        return {"store_puts": 0, "store_recycled": 0}
+    return {"store_puts": store.stats["puts"],
+            "store_recycled": store.stats["recycled"]}
+
+
 def _partial_node(rt, key: str) -> Optional[str]:
     """Which node a published partial physically lives on (None for
     single-node runtimes, where agg ids name logical nodes only)."""
@@ -515,6 +525,10 @@ class RoundOutcome:
     deadline_hit: bool = False
     cold_starts: int = 0
     warm_starts: int = 0
+    # the runtime's in-proc store over the round: puts, and puts served
+    # from its free list (0 where the runtime has no in-proc store)
+    store_puts: int = 0
+    store_recycled: int = 0
     workers: int = 0
     exec_s: Dict[str, float] = field(default_factory=dict)  # agg_id → E
     dispatched: Dict[str, int] = field(default_factory=dict)  # node → n
@@ -777,6 +791,7 @@ class RoundDriver:
             st.job = st.job or st.tag_job
         stats0 = {k: rt.stats.get(k, 0)
                   for k in ("cold_starts", "warm_starts")}
+        stats0.update(_store_counts(rt))
         self._inflight[round_id] = st
         tok_round = self.tracer.begin("round", owner="driver",
                                       round_id=round_id)
@@ -1569,5 +1584,9 @@ class RoundHandle:
             - self._stats0["cold_starts"]
         out.warm_starts = rt.stats.get("warm_starts", 0) \
             - self._stats0["warm_starts"]
+        store = _store_counts(rt)
+        out.store_puts = store["store_puts"] - self._stats0["store_puts"]
+        out.store_recycled = store["store_recycled"] \
+            - self._stats0["store_recycled"]
         out.workers = rt.worker_count()
         self.phase = st.phase = "done"
