@@ -115,21 +115,65 @@ def _enable_cache(cache_dir: Path) -> None:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
+def with_overrides(base, overrides: Dict[str, Any], where: str):
+    """``base`` (a frozen dataclass of the program's registry) with the
+    configuration's ``overrides``: top-level fields replaced, a nested
+    dataclass (``moe``, ``mla``, ``ssm``) overridden field by field, a
+    JSON list taken as a tuple.  A field ``base`` lacks is an error."""
+    import dataclasses
+
+    fields = {f.name for f in dataclasses.fields(base)}
+    changes = {}
+    for key, value in overrides.items():
+        if key not in fields:
+            raise ValueError(f"{where}: unknown override field {key!r} "
+                             f"(not a field of {type(base).__name__})")
+        if isinstance(value, dict):
+            inner = getattr(base, key)
+            if not dataclasses.is_dataclass(inner):
+                raise ValueError(f"{where}: override field {key!r} is an "
+                                 f"object, but {type(base).__name__}."
+                                 f"{key} is {inner!r}")
+            value = with_overrides(inner, value, f"{where}.{key}")
+        elif isinstance(value, list):
+            value = tuple(value)
+        changes[key] = value
+    return dataclasses.replace(base, **changes)
+
+
+def _program_model(cfg: Dict[str, Any]):
+    """``architecture: "resnet"`` builds a ResNet from the file's own
+    keys; any other value is an id of ``repro.configs.ARCHS``, built by
+    ``repro.models.build_model`` with the file's ``overrides``."""
+    if cfg["architecture"] == "resnet":
+        from repro.configs.resnet import ResNetConfig
+        from repro.models import build_resnet
+
+        return build_resnet(ResNetConfig(
+            name=cfg["name"], block=cfg["block"],
+            stage_blocks=tuple(cfg["stage_blocks"]),
+            width=int(cfg["width"]), num_classes=int(cfg["num_classes"]),
+            in_channels=int(cfg["in_channels"]),
+            image_size=int(cfg["image_size"])))
+    from repro.configs import get_arch
+    from repro.models import build_model as build_arch
+
+    arch = with_overrides(get_arch(cfg["architecture"]),
+                          cfg.get("overrides", {}), cfg["name"])
+    return build_arch(arch)
+
+
 def build_model(cfg: Dict[str, Any]):
     """The configuration's model through the program's own builder;
-    its leaf count and size must be the configuration's."""
+    its leaf count and size must be the configuration's.  The update
+    pool is f32, so only ``update_dtype: "float32"`` is taken."""
     import jax
 
-    from repro.configs.resnet import ResNetConfig
-    from repro.models import build_resnet
-
-    rc = ResNetConfig(name=cfg["name"], block=cfg["block"],
-                      stage_blocks=tuple(cfg["stage_blocks"]),
-                      width=int(cfg["width"]),
-                      num_classes=int(cfg["num_classes"]),
-                      in_channels=int(cfg["in_channels"]),
-                      image_size=int(cfg["image_size"]))
-    model = build_resnet(rc)
+    if cfg.get("update_dtype") != "float32":
+        raise ValueError(f"{cfg['name']}: update_dtype "
+                         f"{cfg.get('update_dtype')!r} is not taken: the "
+                         f"harness submits float32 updates only")
+    model = _program_model(cfg)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     leaves = jax.tree.leaves(shapes)
     n = int(sum(int(np.prod(s.shape)) for s in leaves))

@@ -1,7 +1,8 @@
-"""A copy of the benchmark with one tiny extra cell, for CPU tests of
-the harness: ``make_root(tmp)`` lays out ``BENCHMARK.json`` and the
-benchmark's files under ``tmp`` and adds ``tiny-<traffic>`` cells of a
-small ResNet, without touching any file of the repository."""
+"""A copy of the benchmark with tiny extra cells, for CPU tests of the
+harness: ``make_root(tmp)`` lays out ``BENCHMARK.json`` and the
+benchmark's files under ``tmp`` and adds the cells of ``TINY_CELLS``
+(a small ResNet, and a small MLA + MoE model of the program's
+registry), without touching any file of the repository."""
 from __future__ import annotations
 
 import json
@@ -11,7 +12,13 @@ from typing import Any, Dict, Optional
 
 from chipbench import spec
 
-TINY_CONFIG = spec.BENCH_DIR / "testdata" / "tiny-resnet.json"
+# configuration file under testdata/ -> its cells, (cell, traffic)
+TINY_CELLS = {
+    "tiny-resnet": (("tiny-backlog", "backlog"), ("tiny-steady", "steady")),
+    "tiny-mla-moe": (("tiny-mla-moe-backlog", "backlog"),),
+}
+METRIC_OF = {"backlog": ("updates_per_s", "updates/s"),
+             "steady": ("publish_p95_s", "s")}
 
 
 def tiny_params(**over: Any) -> Dict[str, Any]:
@@ -30,25 +37,26 @@ def make_root(tmp: Path, params: Optional[Dict[str, Any]] = None) -> Path:
                     ignore=shutil.ignore_patterns(".jax_cache", ".trace",
                                                   "__pycache__"))
     doc = json.loads((spec.ROOT / "BENCHMARK.json").read_text("utf-8"))
-    doc["configs"].append({
-        "name": "tiny-resnet", "source": "https://arxiv.org/abs/1512.03385",
-        "file": str(spec.REL / "testdata" / "tiny-resnet.json"),
-        "reduced": ["stage_blocks", "width", "num_classes"],
-        "why": "CPU tests"})
-    for traffic, metric, unit in (("backlog", "updates_per_s", "updates/s"),
-                                  ("steady", "publish_p95_s", "s")):
-        name = f"tiny-{traffic}"
-        doc["workloads"].append({"name": name, "config": "tiny-resnet",
-                                 "traffic": traffic, "chips": 1,
-                                 "why": "CPU tests"})
-        entry = next((m for m in doc["end_to_end"] if m["name"] == metric),
-                     None)
-        if entry is None:
-            entry = {"name": metric, "unit": unit, "better": "lower",
-                     "bound": 0.25, "source": "host_clock", "workloads": []}
-            doc["end_to_end"].append(entry)
-        entry["workloads"].append(name)
-        (bench / "cells" / f"{name}.json").write_text(
-            json.dumps(params or tiny_params()), "utf-8")
+    for stem, cells in TINY_CELLS.items():
+        rel = spec.REL / "testdata" / f"{stem}.json"
+        cfg = json.loads((root / rel).read_text("utf-8"))
+        doc["configs"].append({"name": stem, "source": cfg["source"],
+                               "file": str(rel), "reduced": cfg["reduced"],
+                               "why": "CPU tests"})
+        for name, traffic in cells:
+            doc["workloads"].append({"name": name, "config": stem,
+                                     "traffic": traffic, "chips": 1,
+                                     "why": "CPU tests"})
+            metric, unit = METRIC_OF[traffic]
+            entry = next((m for m in doc["end_to_end"]
+                          if m["name"] == metric), None)
+            if entry is None:
+                entry = {"name": metric, "unit": unit, "better": "lower",
+                         "bound": 0.25, "source": "host_clock",
+                         "workloads": []}
+                doc["end_to_end"].append(entry)
+            entry["workloads"].append(name)
+            (bench / "cells" / f"{name}.json").write_text(
+                json.dumps(params or tiny_params()), "utf-8")
     (root / "BENCHMARK.json").write_text(json.dumps(doc), "utf-8")
     return root

@@ -44,3 +44,9 @@ def test_share_of_recycled_puts_over_the_window():
 ], ids=["parent", "mixed", "empty", "no-puts"])
 def test_nothing_where_there_is_nothing_to_read(outcomes):
     assert _read(_ctx(outcomes)) is None
+
+
+@pytest.mark.parametrize("cell", ["r18-backlog", "r152-backlog"])
+def test_share_is_read_in_both_backlog_cells(cell):
+    units = {m.name: m.unit for m in spec.load_cell(cell).per_layer}
+    assert units["store_recycle_share"] == "%"

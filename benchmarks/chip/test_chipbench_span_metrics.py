@@ -86,6 +86,7 @@ def test_span_readers_return_nothing_where_the_program_has_no_such_span():
 def test_span_metrics_are_in_the_backlog_cell():
     cell = spec.load_cell("r18-backlog")
     names = [m.name for m in cell.per_layer]
-    assert names[-len(NAMES):] == list(NAMES)
+    # all five, in their order; later metrics may follow them
+    assert [m for m in names if m in NAMES] == list(NAMES)
     units = {m.name: m.unit for m in cell.per_layer}
     assert units["h2d_gbps"] == "GB/s" and units["queue_wait_p95_s"] == "s"
